@@ -90,6 +90,9 @@ def main() -> None:
     import jax
 
     from benchmarks import tables
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     benches = {
         "fig1": tables.fig1_overall_effectiveness,

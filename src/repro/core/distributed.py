@@ -30,10 +30,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import shard_map
+from jax import shard_map
 
+from repro.core.exact import CERTIFIED_PRECISION
 from repro.core.prohd import ProHDConfig
-from repro.core import selection as sel_mod
+from repro.core import projections, selection as sel_mod
 
 __all__ = ["distributed_prohd", "distributed_exact_hd", "ShardedCloud"]
 
@@ -122,7 +123,9 @@ def _queries_vs_sharded_db(queries, q_valid, db, db_valid, axes, block=2048):
     d32 = db.astype(jnp.float32)
     a2 = jnp.sum(a32 * a32, axis=1, keepdims=True)
     d2n = jnp.sum(d32 * d32, axis=1)
-    d2 = a2 - 2.0 * jnp.matmul(a32, d32.T, preferred_element_type=jnp.float32) + d2n[None, :]
+    d2 = a2 - 2.0 * jnp.matmul(
+        a32, d32.T, precision=CERTIFIED_PRECISION, preferred_element_type=jnp.float32
+    ) + d2n[None, :]
     d2 = jnp.maximum(d2, 0.0)
     d2 = jnp.where(db_masked_valid[None, :], d2, _POS)
     local_min = jnp.min(d2, axis=1)                        # (n_q,)
@@ -159,8 +162,8 @@ def distributed_prohd(
 
     def shard_fn(ap, av, bp, bv):
         dirs = _global_gram_directions(ap, av, bp, bv, m, axes)
-        proj_a = jnp.matmul(ap, dirs, preferred_element_type=jnp.float32)
-        proj_b = jnp.matmul(bp, dirs, preferred_element_type=jnp.float32)
+        proj_a = projections.project(ap, dirs)
+        proj_b = projections.project(bp, dirs)
         mask_a = _select_local_mask(proj_a, av, n_a, cfg.alpha, alpha_pca, axes)
         mask_b = _select_local_mask(proj_b, bv, n_b, cfg.alpha, alpha_pca, axes)
 
@@ -222,7 +225,10 @@ def distributed_exact_hd(
             mins, blk, blk_valid = carry
             b32 = blk.astype(jnp.float32)
             b2 = jnp.sum(b32 * b32, axis=1)
-            d2 = q2 - 2.0 * jnp.matmul(q32, b32.T, preferred_element_type=jnp.float32) + b2[None, :]
+            d2 = q2 - 2.0 * jnp.matmul(
+                q32, b32.T, precision=CERTIFIED_PRECISION,
+                preferred_element_type=jnp.float32,
+            ) + b2[None, :]
             d2 = jnp.maximum(d2, 0.0)
             d2 = jnp.where(blk_valid[None, :], d2, _POS)
             mins = jnp.minimum(mins, jnp.min(d2, axis=1))

@@ -52,6 +52,14 @@ __all__ = [
 _NEG = -jnp.inf
 _POS = jnp.inf
 
+# Precision of every GEMM whose result a certificate rests on: the
+# distance GEMM ``a·bᵀ`` here, in the masked paths and in the Pallas
+# kernels, and the projections.  The fp margins (``index.cascade.fp_margin``)
+# are derived for true fp32 products; a TPU runs an f32 matmul at DEFAULT
+# precision as a single bf16 pass, whose ~2⁻⁸ relative error is far
+# outside them.  The CPU computes f32 products at any setting.
+CERTIFIED_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def pairwise_sqdist(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Squared L2 distances, (n_a, n_b), fp32, clamped ≥ 0."""
@@ -59,7 +67,9 @@ def pairwise_sqdist(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     b = b.astype(jnp.float32)
     a2 = jnp.sum(a * a, axis=1, keepdims=True)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)
-    d2 = a2 - 2.0 * jnp.matmul(a, b.T, preferred_element_type=jnp.float32) + b2.T
+    d2 = a2 - 2.0 * jnp.matmul(
+        a, b.T, precision=CERTIFIED_PRECISION, preferred_element_type=jnp.float32
+    ) + b2.T
     return jnp.maximum(d2, 0.0)
 
 
@@ -124,7 +134,8 @@ def directed_hd_tiled(
 
     def tile_min(cur, bt, b2t):
         d2 = a2[:, None] - 2.0 * jnp.matmul(
-            a32, bt.astype(jnp.float32).T, preferred_element_type=jnp.float32
+            a32, bt.astype(jnp.float32).T,
+            precision=CERTIFIED_PRECISION, preferred_element_type=jnp.float32
         ) + b2t[None, :]
         d2 = jnp.maximum(d2, 0.0)
         return jnp.minimum(cur, jnp.min(d2, axis=1))
@@ -212,7 +223,8 @@ def fused_min_sqdists_tiled(
 
     def tile_mins(row_min, at, a2t, bt, b2t):
         d2 = a2t[:, None] - 2.0 * jnp.matmul(
-            at, bt.T, preferred_element_type=jnp.float32
+            at, bt.T, precision=CERTIFIED_PRECISION,
+            preferred_element_type=jnp.float32
         ) + b2t[None, :]
         d2 = jnp.maximum(d2, 0.0)
         row_min = jnp.minimum(row_min, jnp.min(d2, axis=1))

@@ -38,7 +38,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import exact, selection
+from repro.core import exact, projections, selection
 
 __all__ = [
     "MaskedCertificate",
@@ -527,8 +527,8 @@ def masked_prohd_certified(
     n_b = b.shape[0]
 
     dirs = masked_direction_set(a, va_f, b, vb_f, m)
-    proj_a = jnp.matmul(a, dirs, preferred_element_type=jnp.float32)
-    proj_b = jnp.matmul(b, dirs, preferred_element_type=jnp.float32)
+    proj_a = projections.project(a, dirs)
+    proj_b = projections.project(b, dirs)
 
     k_a = selection.alpha_count(n_a, alpha)
     k_b = selection.alpha_count(n_b, alpha)

@@ -25,6 +25,8 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
+from repro.core.exact import CERTIFIED_PRECISION
+
 PCAMethod = Literal["gram", "rsvd", "subspace"]
 
 __all__ = [
@@ -64,7 +66,10 @@ def project(points: jnp.ndarray, directions: jnp.ndarray) -> jnp.ndarray:
     """
     if directions.ndim == 1:
         directions = directions[:, None]
-    return jnp.matmul(points, directions, preferred_element_type=jnp.float32)
+    return jnp.matmul(
+        points, directions,
+        precision=CERTIFIED_PRECISION, preferred_element_type=jnp.float32,
+    )
 
 
 # ---------------------------------------------------------------------------

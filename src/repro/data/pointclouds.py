@@ -16,6 +16,7 @@ __all__ = [
     "higgs_like",
     "make_dataset",
     "clustered_sets",
+    "late_interaction_sets",
 ]
 
 
@@ -102,6 +103,49 @@ def clustered_sets(
         pts = centers[labels[i]] + rng.randn(n, d).astype(np.float32) * sigma
         sets.append(pts)
     return sets, labels
+
+
+def late_interaction_sets(seed: int, n_sets: int, n_queries: int):
+    """Late-interaction CORPUS with ColBERTv2 shapes (arXiv:2112.01488).
+
+    Each set is one passage: 32–180 unit-norm 128-dim token vectors
+    (ColBERT's doc_maxlen is 180), lengths log-normal around 75 (an MS
+    MARCO passage is ~75 word pieces).  Each query is 32 unit vectors
+    (ColBERT pads every query to 32).  Token vectors are
+    ``normalize(c_t + 0.5 · g)`` around the passage's topic centroid
+    ``c_t``, with ``g`` of unit expected norm and 50 passages a topic; a
+    query is drawn from the topic of a random passage, so its nearest sets
+    are that topic's passages.
+
+    Returns ``(sets, queries)``: lists of float32 numpy arrays.  Generated
+    in bulk from one ``numpy`` Generator, so corpus construction costs a
+    few vectorised passes, not one draw per set.
+    """
+    import numpy as np
+
+    d, min_len, max_len, mean_len, query_len = 128, 32, 180, 75.0, 32
+    sets_per_topic, noise = 50, 0.5
+
+    rng = np.random.default_rng(seed)
+    n_topics = max(1, n_sets // sets_per_topic)
+    centers = rng.standard_normal((n_topics, d), dtype=np.float32)
+    centers /= np.linalg.norm(centers, axis=1, keepdims=True)
+    lens = np.exp(rng.normal(np.log(mean_len), 0.4, size=n_sets))
+    lens = np.clip(np.rint(lens), min_len, max_len).astype(np.int64)
+    topics = rng.integers(0, n_topics, size=n_sets)
+    q_topics = topics[rng.integers(0, n_sets, size=n_queries)]
+
+    def tokens(topic_of_row):
+        x = rng.standard_normal((topic_of_row.size, d), dtype=np.float32)
+        x *= noise / np.sqrt(d)
+        x += centers[topic_of_row]
+        x /= np.linalg.norm(x, axis=1, keepdims=True)
+        return x
+
+    corpus = tokens(np.repeat(topics, lens))
+    sets = np.split(corpus, np.cumsum(lens)[:-1])
+    queries = np.split(tokens(np.repeat(q_topics, query_len)), n_queries)
+    return sets, queries
 
 
 def make_dataset(name: str, key: jax.Array, n_a: int, n_b: int, d: int, **kw):

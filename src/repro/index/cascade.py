@@ -412,15 +412,54 @@ def _stage2_batch(
         )
 
 
+# Stage 1 holds a few (n_q + cap, D) fp32 temporaries per lane (the centred
+# concat, its Gram matrix, the eigh workspace): compiled for a v5e at
+# n_q = 32, cap = 128, D = 128, a 32768-lane launch needs 9.1 GB of
+# temporaries next to the resident corpus.  One launch takes at most this
+# many (n_q + cap) · D elements summed over its lanes (~1.1 GB there).
+_STAGE1_LAUNCH_ELEMS = 1 << 27
+
+
+def _stage1_certs(q, bucket, rows, *, alpha, m, directed, backend, shard_ctx=None):
+    """Masked ProHD certificates of ``rows`` of one bucket, as numpy
+    fields of length ``rows.size``, in launches of at most
+    ``_STAGE1_LAUNCH_ELEMS`` elements: a power of two of lanes, with a
+    short last chunk padded to the full width so that a chunked bucket
+    compiles one shape."""
+    per_lane = (int(q.shape[0]) + bucket.capacity) * int(q.shape[1])
+    lanes = 1 << max(0, (_STAGE1_LAUNCH_ELEMS // per_lane).bit_length() - 1)
+    parts = []
+    for lo in range(0, rows.size, lanes):
+        chunk = rows[lo:lo + lanes]
+        if shard_ctx is not None:
+            from repro.index import sharded as _sharded  # lazy: avoids cycle
+
+            cert = _sharded.stage1_certs(
+                shard_ctx, q, bucket, chunk,
+                alpha=alpha, m=m, directed=directed, backend=backend,
+            )
+        else:
+            take = _pow2_take(chunk, lanes if rows.size > lanes else None)
+            cert = _stage1_batch(
+                q,
+                jnp.take(bucket.points, take, axis=0),
+                jnp.take(bucket.valid, take, axis=0),
+                alpha=alpha, m=m, directed=directed, backend=backend,
+            )
+        parts.append([np.asarray(f)[: chunk.size] for f in cert])
+    return type(cert)(*(np.concatenate(f) for f in zip(*parts)))
+
+
 def _kth_smallest(ub: np.ndarray, k: int) -> float:
     return float(np.partition(ub, k - 1)[k - 1])
 
 
-def _pow2_take(rows: np.ndarray) -> jnp.ndarray:
-    """Gather indices padded to a power of two by repeating row 0 — THE
-    jit-cache discipline for every batched slab gather (stage 1 and stage
-    2a share it); callers slice results back to ``rows.size``."""
-    pad = bucket_capacity(rows.size, 1) - rows.size
+def _pow2_take(rows: np.ndarray, width: int | None = None) -> jnp.ndarray:
+    """Gather indices padded to a power of two (or to ``width``) by
+    repeating row 0 — THE jit-cache discipline for every batched slab
+    gather (stage 1 and stage 2a share it); callers slice results back to
+    ``rows.size``."""
+    pad = (bucket_capacity(rows.size, 1) if width is None else width) - rows.size
     return jnp.asarray(np.concatenate([rows, np.full((pad,), rows[0])]))
 
 
@@ -986,18 +1025,15 @@ def _search_impl(
                         if rows.size == 0:
                             continue
                         checkpoint()
-                        take = _pow2_take(rows)
-                        cert = _with_backend(lambda be: _stage1_batch(
-                            q,
-                            jnp.take(bucket.points, take, axis=0),
-                            jnp.take(bucket.valid, take, axis=0),
+                        cert = _with_backend(lambda be: _stage1_certs(
+                            q, bucket, rows,
                             alpha=cfg.alpha, m=m, directed=directed, backend=be,
                         ))
-                        lo1 = np.maximum(np.asarray(cert.hd), np.asarray(cert.lower))
+                        lo1 = np.maximum(cert.hd, cert.lower)
                         sids = bucket.set_ids[rows]
                         lb1, ub1 = certified_margins(
-                            lo1.astype(np.float64)[: rows.size],
-                            np.asarray(cert.upper, np.float64)[: rows.size],
+                            lo1.astype(np.float64),
+                            cert.upper.astype(np.float64),
                             scale[sids],
                             store.dim,
                         )
@@ -1116,25 +1152,16 @@ def _search_impl(
                         if rows.size == 0:
                             continue
                         checkpoint()
-                        if shard_ctx is not None:
-                            cert = _with_backend(lambda be: _sharded.stage1_certs(
-                                shard_ctx, q, bucket, rows,
-                                alpha=cfg.alpha, m=m, directed=directed,
-                                backend=be,
-                            ))
-                        else:
-                            take = _pow2_take(rows)
-                            cert = _with_backend(lambda be: _stage1_batch(
-                                q,
-                                jnp.take(bucket.points, take, axis=0),
-                                jnp.take(bucket.valid, take, axis=0),
-                                alpha=cfg.alpha, m=m, directed=directed, backend=be,
-                            ))
-                        lo1 = np.maximum(np.asarray(cert.hd), np.asarray(cert.lower))
+                        cert = _with_backend(lambda be: _stage1_certs(
+                            q, bucket, rows,
+                            alpha=cfg.alpha, m=m, directed=directed, backend=be,
+                            shard_ctx=shard_ctx,
+                        ))
+                        lo1 = np.maximum(cert.hd, cert.lower)
                         sids = bucket.set_ids[rows]
                         lb1, ub1 = certified_margins(
-                            lo1.astype(np.float64)[: rows.size],
-                            np.asarray(cert.upper, np.float64)[: rows.size],
+                            lo1.astype(np.float64),
+                            cert.upper.astype(np.float64),
                             scale[sids],
                             store.dim,
                         )

@@ -47,7 +47,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import masked
 from repro.index import cascade as _cascade
 from repro.index.store import bucket_capacity
-from repro.sharding.compat import shard_map
+from jax import shard_map
 
 __all__ = [
     "ShardContext",

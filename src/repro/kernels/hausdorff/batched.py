@@ -34,11 +34,15 @@ Lanes the gate does NOT skip are computed by the identical op sequence as a
 gate-off launch, so their bits are unchanged (pinned by the conformance
 suite); ``cut = +inf`` disables the gate entirely.
 
-Layout: grid = (S, n_q/Ba, cap/Bb), j innermost.  The row-min output block
-(1, Ba) at (s, i) stays VMEM-resident across the j sweep; the col-min
-output row (1, cap) at (s, 0) stays resident across the whole (i, j) sweep
-of its set, each step read-modify-writing its Bb-aligned lane slice.  Both
-revisit patterns are consecutive, so no output flush races a refetch.
+Layout: grid = (S, n_q/Ba, cap/Bb), j innermost.  Per-set rows are stored
+(S, 1, ·) with the set axis squeezed from each block: the TPU compiler
+tiles a block's last two dims by (8, 128) unless they span the array, so
+it refuses a (1, Bb) block of an (S, cap) array but accepts one of
+(S, 1, cap).  The row-min output block
+(1, Ba) at (s, 0, i) stays VMEM-resident across the j sweep; the col-min
+output row (1, cap) at (s, 0, 0) stays resident across the whole (i, j)
+sweep of its set, each step read-modify-writing its Bb-aligned lane slice.
+Both revisit patterns are consecutive, so no output flush races a refetch.
 
 VMEM per step (fp32): q tile Ba·D + b tile Bb·D + d² tile Ba·Bb + norm rows
 + the resident (1, cap) col-min row — bucket capacities are ≤ a few
@@ -54,8 +58,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import exact
-from repro.kernels.hausdorff.ops import fit_block
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
+from repro.core.exact import CERTIFIED_PRECISION
+from repro.kernels.hausdorff.ops import _default_interpret, _pad_axis, fit_block
 
 __all__ = [
     "batched_min_sqdists_pallas",
@@ -69,6 +73,13 @@ __all__ = [
 ]
 
 _INF = float("inf")  # python float: jnp constants would become kernel consts
+
+# The gate operands ride in scalar-prefetch SMEM, 1 MiB on a v5e for every
+# scalar a kernel keeps: at 8 queries × 65536 sets one (Q, S) fp32 gate
+# operand alone is 2 MiB, which the TPU compiler refuses.  A launch gates
+# at most this many (query, set) lanes; a larger slab runs as several
+# launches over consecutive set ranges, each reading the slab in place.
+GATE_LANES = 1 << 16
 
 
 def _batched_kernel(
@@ -106,6 +117,7 @@ def _batched_kernel(
             q,
             b,
             dimension_numbers=(((1,), (1,)), ((), ())),  # q @ b.T
+            precision=CERTIFIED_PRECISION,
             preferred_element_type=jnp.float32,
         )
         # +inf norms poison invalid rows/cols in both directions at once.
@@ -115,8 +127,8 @@ def _batched_kernel(
         mina_ref[...] = jnp.minimum(mina_ref[...], tile_row_min)
 
         tile_col_min = jnp.min(d2, axis=0)[None, :]                  # (1, Bb)
-        sl = (slice(None), pl.dslice(pl.multiple_of(j * block_b, block_b), block_b))
-        pl.store(minb_ref, sl, jnp.minimum(pl.load(minb_ref, sl), tile_col_min))
+        sl = (slice(None), pl.ds(pl.multiple_of(j * block_b, block_b), block_b))
+        minb_ref[sl] = jnp.minimum(minb_ref[sl], tile_col_min)
 
 
 @functools.partial(jax.jit, static_argnames=("block_a", "block_b", "interpret"))
@@ -137,61 +149,55 @@ def batched_min_sqdists_pallas(
     Preconditions (enforced by :func:`batched_min_sqdists`): ``q`` is
     (n_q_pad, D) with n_q_pad % block_a == 0 and D % 128 == 0 (or small-D
     padded); ``slab`` is (S, cap_pad, D) with cap_pad % block_b == 0;
-    ``q2`` (n_q_pad, 1) / ``b2`` (S, cap_pad) are hoisted squared norms
+    ``q2`` (n_q_pad, 1) / ``b2`` (S, 1, cap_pad) are hoisted squared norms
     with +inf at invalid/padded rows; ``lb``/``cut`` are (S,) fp32 gate
     operands in any consistent units (``cut = +inf`` disables the gate).
 
-    Returns ``(min_a, min_b)``: (S, n_q_pad) per-query min d² against each
-    set's valid rows, and (S, cap_pad) per-row min d² against the valid
-    query rows, both fp32.  Gated-out lanes are +inf throughout; rows that
-    are themselves invalid come back +inf and must be masked by the caller
-    before any max-reduce.
+    Returns ``(min_a, min_b)``: (S, 1, n_q_pad) per-query min d² against
+    each set's valid rows, and (S, 1, cap_pad) per-row min d² against the
+    valid query rows, both fp32.  Gated-out lanes are +inf throughout;
+    rows that are themselves invalid come back +inf and must be masked by
+    the caller before any max-reduce.  The unit middle axis is there for
+    the TPU tiling (module docstring, "Layout").
     """
     n_q, d = q.shape
     s_sets, cap = slab.shape[0], slab.shape[1]
-    grid = (s_sets, n_q // block_a, cap // block_b)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_a, d), lambda s, i, j, *_: (i, 0)),
-            pl.BlockSpec((1, block_b, d), lambda s, i, j, *_: (s, j, 0)),
-            pl.BlockSpec((block_a, 1), lambda s, i, j, *_: (i, 0)),
-            pl.BlockSpec((1, block_b), lambda s, i, j, *_: (s, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_a), lambda s, i, j, *_: (s, i)),
-            pl.BlockSpec((1, cap), lambda s, i, j, *_: (s, 0)),
-        ],
-    )
-    mina, minb = pl.pallas_call(
-        functools.partial(_batched_kernel, block_b=block_b),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((s_sets, n_q), jnp.float32),
-            jax.ShapeDtypeStruct((s_sets, cap), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
-        ),
-        interpret=interpret,
-    )(lb, cut, q, slab, q2, b2)
-    return mina, minb
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _pad_axis(x, mult, axis, value=0.0):
-    n = x.shape[axis]
-    pad = (-n) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
+    parts = []
+    for s0 in range(0, s_sets, GATE_LANES):
+        n = min(GATE_LANES, s_sets - s0)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n, n_q // block_a, cap // block_b),
+            in_specs=[
+                pl.BlockSpec((block_a, d), lambda s, i, j, *_: (i, 0)),
+                pl.BlockSpec(
+                    (1, block_b, d), lambda s, i, j, *_, s0=s0: (s + s0, j, 0)
+                ),
+                pl.BlockSpec((block_a, 1), lambda s, i, j, *_: (i, 0)),
+                pl.BlockSpec(
+                    (None, 1, block_b), lambda s, i, j, *_, s0=s0: (s + s0, 0, j)
+                ),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, 1, block_a), lambda s, i, j, *_: (s, 0, i)),
+                pl.BlockSpec((None, 1, cap), lambda s, i, j, *_: (s, 0, 0)),
+            ],
+        )
+        parts.append(pl.pallas_call(
+            functools.partial(_batched_kernel, block_b=block_b),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((n, 1, n_q), jnp.float32),
+                jax.ShapeDtypeStruct((n, 1, cap), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
+            ),
+            interpret=interpret,
+        )(lb[s0:s0 + n], cut[s0:s0 + n], q, slab, q2, b2))
+    if len(parts) == 1:
+        return parts[0]
+    return tuple(jnp.concatenate(p, axis=0) for p in zip(*parts))
 
 
 @functools.partial(
@@ -265,10 +271,10 @@ def batched_min_sqdists(
     b2 = jnp.where(vb_p > 0.0, b2, jnp.inf)
 
     mina, minb = batched_min_sqdists_pallas(
-        q_p, s_p, q2, b2, lb, cut,
+        q_p, s_p, q2, b2[:, None, :], lb, cut,
         block_a=block_a, block_b=block_b, interpret=interpret,
     )
-    return mina[:, :n_q], minb[:, :cap]
+    return mina[:, 0, :n_q], minb[:, 0, :cap]
 
 
 @functools.partial(jax.jit, static_argnames=("block_a", "block_b"))
@@ -382,8 +388,8 @@ def _multiquery_kernel(
     b_ref,       # (1, Bb, D) slab block of set s — shared across queries
     q2_ref,      # (1, Ba, 1) hoisted ||q||²; +inf ⇒ row invalid/padded
     b2_ref,      # (1, Bb) hoisted ||b||²; +inf ⇒ row invalid/padded
-    mina_ref,    # out (1, 1, Ba) block of (qq, s) — revisited across j
-    minb_ref,    # out (1, 1, cap) row of (qq, s) — resident across (i, j)
+    mina_ref,    # out (1, Ba) block of (qq, s) — revisited across j
+    minb_ref,    # out (1, cap) row of (qq, s) — resident across (i, j)
     *,
     block_b: int,
 ):
@@ -411,20 +417,17 @@ def _multiquery_kernel(
             q,
             b,
             dimension_numbers=(((1,), (1,)), ((), ())),  # q @ b.T
+            precision=CERTIFIED_PRECISION,
             preferred_element_type=jnp.float32,
         )
         d2 = jnp.maximum(q2_ref[0] - 2.0 * qb + b2_ref[...], 0.0)  # (Ba, Bb)
 
-        tile_row_min = jnp.min(d2, axis=1)[None, None, :]          # (1, 1, Ba)
+        tile_row_min = jnp.min(d2, axis=1)[None, :]                # (1, Ba)
         mina_ref[...] = jnp.minimum(mina_ref[...], tile_row_min)
 
-        tile_col_min = jnp.min(d2, axis=0)[None, None, :]          # (1, 1, Bb)
-        sl = (
-            slice(None),
-            slice(None),
-            pl.dslice(pl.multiple_of(j * block_b, block_b), block_b),
-        )
-        pl.store(minb_ref, sl, jnp.minimum(pl.load(minb_ref, sl), tile_col_min))
+        tile_col_min = jnp.min(d2, axis=0)[None, :]                # (1, Bb)
+        sl = (slice(None), pl.ds(pl.multiple_of(j * block_b, block_b), block_b))
+        minb_ref[sl] = jnp.minimum(minb_ref[sl], tile_col_min)
 
 
 @functools.partial(jax.jit, static_argnames=("block_a", "block_b", "interpret"))
@@ -445,45 +448,61 @@ def multiquery_min_sqdists_pallas(
     Preconditions (enforced by :func:`multiquery_min_sqdists`): ``qs`` is
     (Q, n_q_pad, D) with n_q_pad % block_a == 0 and D % 128 == 0; ``slab``
     is (S, cap_pad, D) with cap_pad % block_b == 0; ``q2`` (Q, n_q_pad, 1) /
-    ``b2`` (S, cap_pad) are hoisted squared norms with +inf at invalid rows;
-    ``lb``/``cut`` are (Q, S) fp32 per-(query, set) gate operands.
+    ``b2`` (S, 1, cap_pad) are hoisted squared norms with +inf at invalid
+    rows; ``lb``/``cut`` are (Q, S) fp32 per-(query, set) gate operands.
 
-    Returns ``(min_a (Q, S, n_q_pad), min_b (Q, S, cap_pad))`` fp32.  The
-    slab block's index map ignores the query coordinate, so consecutive
-    grid steps that differ only in their inner sweep reuse the fetched slab
-    block — the query batch shares each slab in one launch.
+    Returns ``(min_a (Q, S, 1, n_q_pad), min_b (Q, S, 1, cap_pad))`` fp32,
+    with the same unit axis as :func:`batched_min_sqdists_pallas` and for
+    the same tiling reason.  The slab block's index map ignores the query
+    coordinate, so consecutive grid steps that differ only in their inner
+    sweep reuse the fetched slab block — the query batch shares each slab
+    in one launch.
     """
     q_batch, n_q, d = qs.shape
     s_sets, cap = slab.shape[0], slab.shape[1]
-    grid = (q_batch, s_sets, n_q // block_a, cap // block_b)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_a, d), lambda qq, s, i, j, *_: (qq, i, 0)),
-            pl.BlockSpec((1, block_b, d), lambda qq, s, i, j, *_: (s, j, 0)),
-            pl.BlockSpec((1, block_a, 1), lambda qq, s, i, j, *_: (qq, i, 0)),
-            pl.BlockSpec((1, block_b), lambda qq, s, i, j, *_: (s, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_a), lambda qq, s, i, j, *_: (qq, s, i)),
-            pl.BlockSpec((1, 1, cap), lambda qq, s, i, j, *_: (qq, s, 0)),
-        ],
-    )
-    mina, minb = pl.pallas_call(
-        functools.partial(_multiquery_kernel, block_b=block_b),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((q_batch, s_sets, n_q), jnp.float32),
-            jax.ShapeDtypeStruct((q_batch, s_sets, cap), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",) * 4
-        ),
-        interpret=interpret,
-    )(lb, cut, qs, slab, q2, b2)
-    return mina, minb
+    step = max(1, GATE_LANES // q_batch)
+    parts = []
+    for s0 in range(0, s_sets, step):
+        n = min(step, s_sets - s0)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(q_batch, n, n_q // block_a, cap // block_b),
+            in_specs=[
+                pl.BlockSpec((1, block_a, d), lambda qq, s, i, j, *_: (qq, i, 0)),
+                pl.BlockSpec(
+                    (1, block_b, d),
+                    lambda qq, s, i, j, *_, s0=s0: (s + s0, j, 0),
+                ),
+                pl.BlockSpec((1, block_a, 1), lambda qq, s, i, j, *_: (qq, i, 0)),
+                pl.BlockSpec(
+                    (None, 1, block_b),
+                    lambda qq, s, i, j, *_, s0=s0: (s + s0, 0, j),
+                ),
+            ],
+            out_specs=[
+                pl.BlockSpec(
+                    (None, None, 1, block_a), lambda qq, s, i, j, *_: (qq, s, 0, i)
+                ),
+                pl.BlockSpec(
+                    (None, None, 1, cap), lambda qq, s, i, j, *_: (qq, s, 0, 0)
+                ),
+            ],
+        )
+        parts.append(pl.pallas_call(
+            functools.partial(_multiquery_kernel, block_b=block_b),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((q_batch, n, 1, n_q), jnp.float32),
+                jax.ShapeDtypeStruct((q_batch, n, 1, cap), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * 4
+            ),
+            interpret=interpret,
+        )(lb[:, s0:s0 + n], cut[:, s0:s0 + n], qs, slab, q2, b2))
+    if len(parts) == 1:
+        return parts[0]
+    return tuple(jnp.concatenate(p, axis=1) for p in zip(*parts))
 
 
 @functools.partial(jax.jit, static_argnames=("block_a", "block_b"))
@@ -612,10 +631,10 @@ def multiquery_min_sqdists(
     b2 = jnp.where(vb_p > 0.0, b2, jnp.inf)
 
     mina, minb = multiquery_min_sqdists_pallas(
-        q_p, s_p, q2, b2, lb, cut,
+        q_p, s_p, q2, b2[:, None, :], lb, cut,
         block_a=block_a, block_b=block_b, interpret=interpret,
     )
-    return mina[:, :, :n_q], minb[:, :, :cap]
+    return mina[:, :, 0, :n_q], minb[:, :, 0, :cap]
 
 
 @functools.partial(

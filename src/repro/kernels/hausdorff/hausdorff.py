@@ -54,8 +54,8 @@ Layout / tiling:
     at j == 0.
   - col-min output: the FULL (1, n_b_pad) row with a constant (0, 0) index
     map, so it stays resident across the entire grid; each step
-    read-modify-writes its own (1, Bb) lane slice with ``pl.load/pl.store``
-    at the Bb-aligned dynamic offset j·Bb.  This avoids non-consecutive
+    read-modify-writes its own (1, Bb) lane slice by ref indexing at the
+    Bb-aligned dynamic offset j·Bb.  This avoids non-consecutive
     output-block revisits (i outer ⇒ block (0, j) would be revisited a full
     j-sweep later, racing the output flush against the refetch).
 
@@ -78,10 +78,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.exact import CERTIFIED_PRECISION
+
 DEFAULT_BLOCK_A = 512
 DEFAULT_BLOCK_B = 512
-
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
 
 _INF = float("inf")  # plain python float: jnp constants would be captured as kernel consts
 
@@ -122,6 +122,7 @@ def _fused_kernel(
             a,
             b,
             dimension_numbers=(((1,), (1,)), ((), ())),  # a @ b.T
+            precision=CERTIFIED_PRECISION,
             preferred_element_type=jnp.float32,
         )
         # +inf norms poison invalid rows/cols: their d² entries are +inf in
@@ -135,8 +136,8 @@ def _fused_kernel(
         # B→A: fold the tile's col mins into this tile's lane slice of the
         # resident full col-min row.
         tile_col_min = jnp.min(d2, axis=0)[None, :]                  # (1, Bb)
-        sl = (slice(None), pl.dslice(pl.multiple_of(j * block_b, block_b), block_b))
-        pl.store(minb_ref, sl, jnp.minimum(pl.load(minb_ref, sl), tile_col_min))
+        sl = (slice(None), pl.ds(pl.multiple_of(j * block_b, block_b), block_b))
+        minb_ref[sl] = jnp.minimum(minb_ref[sl], tile_col_min)
 
 
 @functools.partial(
@@ -193,7 +194,7 @@ def fused_min_sqdists_pallas(
             jax.ShapeDtypeStruct((1, n_a), jnp.float32),
             jax.ShapeDtypeStruct((1, n_b), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,

@@ -20,7 +20,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import GNNConfig
 from repro.sharding.axes import MeshRules, shard

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import shard_map
+from jax import shard_map
 
 from repro.sharding.axes import MeshRules, current_rules
 

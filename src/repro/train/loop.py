@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.sharding.compat import shard_map
+from jax import shard_map
 
 from repro.train import checkpoint as ckpt_mod
 from repro.train import compression as comp_mod

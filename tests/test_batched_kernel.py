@@ -164,6 +164,35 @@ def test_vmapped_single_pair_view_equals_native_slab():
     np.testing.assert_array_equal(vmapped, native)
 
 
+@pytest.mark.parametrize("multi", [False, True], ids=["batched", "multiquery"])
+def test_gate_lanes_split_across_launches(monkeypatch, multi):
+    """A slab with more gate lanes than one launch's SMEM holds runs as
+    several launches over consecutive set ranges, bit for bit the values
+    of a single launch (eager, so the patched limit is read on each call)."""
+    q, raws, pts, val = _slab(seed=5, batch=7, cap=16, d=5, nq=9)
+    lb = jnp.asarray(np.arange(7, dtype=np.float32))
+    cut = jnp.full((7,), 4.5, jnp.float32)
+
+    def run():
+        if multi:
+            qs = jnp.stack([q, q[::-1], q * 0.5])
+            return batched.multiquery_min_sqdists(
+                qs, pts, valid_slab=val, lb=jnp.tile(lb, (3, 1)),
+                cut=jnp.tile(cut, (3, 1)), block_a=64, block_b=64,
+            )
+        return batched.batched_min_sqdists(
+            q, pts, valid_slab=val, lb=lb, cut=cut, block_a=64, block_b=64
+        )
+
+    with jax.disable_jit():
+        whole = run()
+        monkeypatch.setattr(batched, "GATE_LANES", 4)
+        split = run()
+    for w, s in zip(whole, split):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(s))
+    assert np.isinf(np.asarray(whole[0])[..., 5:, :]).all()
+
+
 @pytest.mark.pallas
 @pytest.mark.skipif(
     jax.default_backend() != "tpu", reason="native Pallas lowering needs a TPU"

@@ -231,6 +231,34 @@ def test_search_prunes_separated_corpus():
     assert res.stats["exact_refines"] < 32
 
 
+def test_stage1_launches_split_by_lane_budget(monkeypatch):
+    """A bucket wider than stage 1's per-launch element budget runs as
+    several equal-width launches; the top-k is unchanged and every
+    frontier lane still gets its certificate."""
+    from repro.index import cascade
+
+    sets, rng = _corpus(11, n_sets=40)
+    q = _query(rng, sets, 4)
+    store = SetStore(dim=4)
+    store.add_many(sets)
+    whole = search(q, store, 4)
+    calls = []
+    real = cascade._stage1_batch
+
+    def spy(qq, pts, valid, **kw):
+        calls.append(pts.shape[0])
+        return real(qq, pts, valid, **kw)
+
+    monkeypatch.setattr(cascade, "_stage1_batch", spy)
+    cap = max(store.bucket_capacities)
+    monkeypatch.setattr(cascade, "_STAGE1_LAUNCH_ELEMS", 2 * (q.shape[0] + cap) * 4)
+    split = search(q, store, 4)
+    np.testing.assert_array_equal(split.ids, whole.ids)
+    np.testing.assert_array_equal(split.values, whole.values)
+    assert split.stats["stage1_pruned"] == whole.stats["stage1_pruned"]
+    assert 2 in calls and len(calls) > len(store.bucket_capacities)
+
+
 def test_front_door_search_is_the_cascade():
     sets, rng = _corpus(9, n_sets=12)
     q = _query(rng, sets, 4)
