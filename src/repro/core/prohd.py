@@ -36,7 +36,15 @@ import jax.numpy as jnp
 
 from repro.core import bounds, exact, projected, projections, selection, tile_bounds
 
-__all__ = ["ProHDConfig", "ProHDEstimate", "prohd", "prohd_masks"]
+__all__ = ["SCOPES", "ProHDConfig", "ProHDEstimate", "prohd", "prohd_masks"]
+
+# The ``jax.named_scope`` of each phase of Alg. 3 inside ``prohd``: the
+# selection (directions, projections, extreme masks, gathers), the two
+# nearest-neighbour sweeps, the additive bound and the certified lower
+# bound.  They change only the ops' ``op_name`` metadata, never the
+# program; ``repro.obs.xla.op_scopes`` maps a profile's device ops to them.
+SCOPES = ("prohd.select", "prohd.nn_scan", "prohd.bound", "prohd.lower_bound")
+SELECT, NN_SCAN, BOUND, LOWER_BOUND = SCOPES
 
 SubsetBackend = Literal["tiled", "dense", "pallas"]
 
@@ -179,56 +187,65 @@ def prohd(a: jnp.ndarray, b: jnp.ndarray, cfg: ProHDConfig = ProHDConfig(), *, k
     if key is None and cfg.pca_method != "gram":
         raise ValueError("randomized PCA backends need key=")
 
-    sel = prohd_masks(a, b, cfg, key=key)
-    mask_a, mask_b, proj_a, proj_b = sel
+    with jax.named_scope(SELECT):
+        mask_a, mask_b, proj_a, proj_b = prohd_masks(a, b, cfg, key=key)
 
-    if cfg.prune:
-        # Reorder each cloud along the primary projection (HD is a set
-        # metric — any consistent permutation is a no-op) so that
-        # block-contiguous rows cover disjoint 1-D ranges and the tile
-        # interval gaps in tile_bounds actually bite.
-        a, proj_a, _, perm_a = tile_bounds.order_by_projection(a, proj_a)
-        b, proj_b, _, perm_b = tile_bounds.order_by_projection(b, proj_b)
-        mask_a = mask_a[perm_a]
-        mask_b = mask_b[perm_b]
+        if cfg.prune:
+            # Reorder each cloud along the primary projection (HD is a set
+            # metric — any consistent permutation is a no-op) so that
+            # block-contiguous rows cover disjoint 1-D ranges and the tile
+            # interval gaps in tile_bounds actually bite.
+            a, proj_a, _, perm_a = tile_bounds.order_by_projection(a, proj_a)
+            b, proj_b, _, perm_b = tile_bounds.order_by_projection(b, proj_b)
+            mask_a = mask_a[perm_a]
+            mask_b = mask_b[perm_b]
 
-    cap_a = selection.selection_capacity(n_a, m, cfg.alpha, cfg.alpha_pca)
-    cap_b = selection.selection_capacity(n_b, m, cfg.alpha, cfg.alpha_pca)
-    a_sel, va = selection.take_selected(a, mask_a, cap_a)
-    b_sel, vb = selection.take_selected(b, mask_b, cap_b)
+        cap_a = selection.selection_capacity(n_a, m, cfg.alpha, cfg.alpha_pca)
+        cap_b = selection.selection_capacity(n_b, m, cfg.alpha, cfg.alpha_pca)
+        a_sel, va = selection.take_selected(a, mask_a, cap_a)
+        b_sel, vb = selection.take_selected(b, mask_b, cap_b)
 
-    if cfg.prune:
-        # Gathering preserves sort order, so the subsets stay
-        # projection-sorted and their prune tables stay effective.
-        proj_a_sel, _ = selection.take_selected(proj_a, mask_a, cap_a)
-        proj_b_sel, _ = selection.take_selected(proj_b, mask_b, cap_b)
-        if cfg.inner == "full":
-            hd = _queries_vs_full_hd(
-                a_sel, va, b_sel, vb, a, b, cfg,
-                projs=(proj_a_sel, proj_b_sel, proj_a, proj_b),
-            )
+        if cfg.prune:
+            # Gathering preserves sort order, so the subsets stay
+            # projection-sorted and their prune tables stay effective.
+            proj_a_sel, _ = selection.take_selected(proj_a, mask_a, cap_a)
+            proj_b_sel, _ = selection.take_selected(proj_b, mask_b, cap_b)
+
+    with jax.named_scope(NN_SCAN):
+        if cfg.prune:
+            if cfg.inner == "full":
+                hd = _queries_vs_full_hd(
+                    a_sel, va, b_sel, vb, a, b, cfg,
+                    projs=(proj_a_sel, proj_b_sel, proj_a, proj_b),
+                )
+            else:
+                hd = _subset_hd(a_sel, va, b_sel, vb, cfg, prune_projs=(proj_a_sel, proj_b_sel))
+        elif cfg.inner == "full":
+            hd = _queries_vs_full_hd(a_sel, va, b_sel, vb, a, b, cfg)
         else:
-            hd = _subset_hd(a_sel, va, b_sel, vb, cfg, prune_projs=(proj_a_sel, proj_b_sel))
-    elif cfg.inner == "full":
-        hd = _queries_vs_full_hd(a_sel, va, b_sel, vb, a, b, cfg)
-    else:
-        hd = _subset_hd(a_sel, va, b_sel, vb, cfg)
+            hd = _subset_hd(a_sel, va, b_sel, vb, cfg)
 
     # NB: use the (possibly permuted) locals so rows of a/proj_a stay aligned.
     if cfg.compute_bound:
-        bound = bounds.additive_bound(a, b, proj_a, proj_b)
+        with jax.named_scope(BOUND):
+            bound = bounds.additive_bound(a, b, proj_a, proj_b)
     else:
         bound = jnp.float32(0.0)
 
     if cfg.compute_projected:
-        hd_proj = projected.projected_hd(proj_a, proj_b)
+        with jax.named_scope(LOWER_BOUND):
+            hd_proj = projected.projected_hd(proj_a, proj_b)
     else:
         hd_proj = jnp.float32(0.0)
 
+    with jax.named_scope(SELECT):
+        n_sel_a = mask_a.sum().astype(jnp.int32)
+        n_sel_b = mask_b.sum().astype(jnp.int32)
+
     return ProHDEstimate(
         hd=hd,
-        n_sel_a=mask_a.sum().astype(jnp.int32),
-        n_sel_b=mask_b.sum().astype(jnp.int32),
+        n_sel_a=n_sel_a,
+        n_sel_b=n_sel_b,
         bound=bound,
         hd_proj=hd_proj,
     )

@@ -30,10 +30,12 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro import obs
 from repro.hd import registry, resolver
 from repro.hd.config import HDConfig
 from repro.hd.methods import DispatchContext
 from repro.hd.result import HDMeta, HDResult
+from repro.obs.trace import _NOOP as _INERT
 
 __all__ = ["set_distance", "HDEngine"]
 
@@ -116,42 +118,53 @@ def set_distance(
     registry.validate_axes(variant, method, backend)
     cfg = config if config is not None else HDConfig()
     valid_a, valid_b = _unpack_masks(masks)
-    if validate:
-        _reject_nonfinite("a", a, valid_a)
-        _reject_nonfinite("b", b, valid_b)
-    n_a, d = a.shape
-    n_b = b.shape[0]
+    # Spans only for concrete calls: under jit/vmap nothing runs here.
+    traced = obs.enabled() and not (isinstance(a, jax.core.Tracer)
+                                    or isinstance(b, jax.core.Tracer))
+    with obs.span("hd.set_distance", variant=variant, method=method) if traced else _INERT as root:
+        if validate:
+            with obs.span("hd.validate") if traced else _INERT as sp:
+                _reject_nonfinite("a", a, valid_a)
+                _reject_nonfinite("b", b, valid_b)
+                if traced:  # what the check copied off the device
+                    sp.set(bytes=sum(int(x.nbytes) for x in (a, b, valid_a, valid_b)
+                                     if isinstance(x, jax.Array)))
+        n_a, d = a.shape
+        n_b = b.shape[0]
 
-    if backend == "auto":
-        n_devices = getattr(mesh, "size", 1) if mesh is not None else 1
-        backend = resolver.resolve_backend(
-            variant, method, n_a, n_b, d,
-            device_kind=resolver.default_device_kind(), n_devices=n_devices,
+        if backend == "auto":
+            n_devices = getattr(mesh, "size", 1) if mesh is not None else 1
+            backend = resolver.resolve_backend(
+                variant, method, n_a, n_b, d,
+                device_kind=resolver.default_device_kind(), n_devices=n_devices,
+            )
+        if traced:
+            root.set(backend=backend, n_a=n_a, n_b=n_b, d=d)
+        impl = registry.resolve(variant, method, backend)
+
+        block_a, block_b = cfg.block_a, cfg.block_b
+        if block_a is None or block_b is None:
+            rba, rbb = resolver.resolve_block_sizes(
+                n_a, n_b, d,
+                device_kind=resolver.default_device_kind(), backend=backend,
+            )
+            block_a = rba if block_a is None else block_a
+            block_b = rbb if block_b is None else block_b
+
+        ctx = DispatchContext(
+            valid_a=valid_a, valid_b=valid_b, key=key, cfg=cfg,
+            block_a=block_a, block_b=block_b, mesh=mesh,
+            batch_axes=tuple(batch_axes), prune_projs=prune_projs,
         )
-    impl = registry.resolve(variant, method, backend)
 
-    block_a, block_b = cfg.block_a, cfg.block_b
-    if block_a is None or block_b is None:
-        rba, rbb = resolver.resolve_block_sizes(
-            n_a, n_b, d,
-            device_kind=resolver.default_device_kind(), backend=backend,
-        )
-        block_a = rba if block_a is None else block_a
-        block_b = rbb if block_b is None else block_b
-
-    ctx = DispatchContext(
-        valid_a=valid_a, valid_b=valid_b, key=key, cfg=cfg,
-        block_a=block_a, block_b=block_b, mesh=mesh,
-        batch_axes=tuple(batch_axes), prune_projs=prune_projs,
-    )
-
-    timing = measure and not isinstance(a, jax.core.Tracer)
-    t0 = time.perf_counter() if timing else 0.0
-    value, lower, upper, stats = impl(a, b, ctx)
-    elapsed = None
-    if timing:
-        jax.block_until_ready(value)
-        elapsed = time.perf_counter() - t0
+        timing = measure and not isinstance(a, jax.core.Tracer)
+        t0 = time.perf_counter() if timing else 0.0
+        with obs.span("hd.dispatch") if traced else _INERT:
+            value, lower, upper, stats = impl(a, b, ctx)
+        elapsed = None
+        if timing:
+            jax.block_until_ready(value)
+            elapsed = time.perf_counter() - t0
 
     meta = HDMeta(
         variant=variant, method=method, backend=backend,

@@ -35,6 +35,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import adaptive as adaptive_mod
 from repro.core import exact, sampling, tile_bounds, variants
 # NB: import the function by module path — the ``repro.core`` package
@@ -279,6 +280,8 @@ def _register_prohd(backend: str):
     def impl(a, b, ctx, *, _backend=backend):
         _reject_masks(ctx, "prohd")
         pc = ctx.cfg.prohd_config(_backend)
+        if obs.enabled():
+            obs.xla.note(_prohd_call, a, b, pc, key=ctx.key)
         est = _prohd_call(a, b, pc, key=ctx.key)
         lower, upper = _prohd_bounds(est, pc)
         stats = {"estimate": est, "n_sel_a": est.n_sel_a, "n_sel_b": est.n_sel_b}

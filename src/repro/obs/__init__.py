@@ -3,9 +3,11 @@
 Spans (:func:`span`, monotonic timing, rid correlation, contextvar
 nesting), a typed :class:`MetricsRegistry` (counters / gauges /
 log-bucket histograms, Prometheus text exposition), a validated JSONL
-export, and report rendering.  Disabled by default; the no-op fast path
-is benchmarked and gated in ``scripts/check.sh``.  See the
-"Observability contract" section of ``docs/api.md``.
+export, report rendering, and :mod:`repro.obs.xla`, which resolves a
+profile's device ops to the ``jax.named_scope`` they ran under.
+Disabled by default; the no-op fast path is benchmarked and gated in
+``scripts/check.sh``.  See the "Observability contract" section of
+``docs/api.md``.
 
 Quick start::
 
@@ -15,7 +17,7 @@ Quick start::
         search(q, store, k=5)
     print(obs.report.stage_table(get_events()))
 """
-from repro.obs import export, metrics, report, trace
+from repro.obs import export, metrics, report, trace, xla
 from repro.obs.export import OBS_SCHEMA_VERSION, SchemaError, read_jsonl, validate_events, write_jsonl
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry, record_stats, registry
 from repro.obs.trace import (
@@ -67,4 +69,5 @@ __all__ = [
     "trace",
     "validate_events",
     "write_jsonl",
+    "xla",
 ]

@@ -147,16 +147,20 @@ def drain() -> list[dict]:
 def capture(*, jsonl=None, xla: bool = False):
     """Test/bench-scoped tracing: enable, yield the live event list getter,
     disable and restore on exit.  Drains pre-existing events so the block
-    sees only its own."""
-    prior_enabled = _STATE.enabled
+    sees only its own.  The tracer's prior settings come back whole: on or
+    off, ``xla``, and a ``jsonl`` export, whose file stays open and
+    receives nothing from the block."""
+    with _STATE.lock:
+        prior = (_STATE.enabled, _STATE.xla, _STATE.jsonl)
+        _STATE.jsonl = None  # detached, so the block's enable() keeps it open
     drain()
     enable(jsonl=jsonl, xla=xla)
     try:
         yield events
     finally:
         disable()
-        if prior_enabled:
-            enable()
+        with _STATE.lock:
+            _STATE.enabled, _STATE.xla, _STATE.jsonl = prior
 
 
 def new_rid() -> str:

@@ -481,3 +481,235 @@ class TestServicePayload:
         assert 0.0 <= out_any["certified_recall"] <= 1.0
         for lo, v, up in zip(out_any["lower"], out_any["values"], out_any["upper"]):
             assert lo <= v + 1e-6 and v - 1e-6 <= up
+
+
+# ---------------------------------------------------------------------------
+# the front door's spans: hd.set_distance -> hd.validate, hd.dispatch
+# ---------------------------------------------------------------------------
+
+
+def _clouds(n=256, d=16, seed=0):
+    import jax
+
+    ka, kb = jax.random.split(jax.random.PRNGKey(seed))
+    a = jax.random.uniform(ka, (n, d))
+    b = jax.random.uniform(kb, (n, d)) + 0.1
+    return a, b
+
+
+def _prohd_call(a, b):
+    from repro.hd import HDConfig, set_distance
+
+    return set_distance(a, b, method="prohd", config=HDConfig(alpha=0.05))
+
+
+class TestFrontDoorSpans:
+    def test_one_call_one_tree_under_the_ambient_span(self):
+        a, b = _clouds()
+        with trace.capture() as get_events:
+            with trace.span("serve.flush") as outer:
+                res = _prohd_call(a, b)
+            events = get_events()
+        validate_events(events)
+        spans = {e["name"]: e for e in events if e["type"] == "span"}
+        root = spans["hd.set_distance"]
+        assert root["parent_id"] == outer.span_id
+        for child in ("hd.validate", "hd.dispatch"):
+            assert spans[child]["parent_id"] == root["span_id"]
+        assert {e["rid"] for e in events} == {outer.rid}
+        assert root["attrs"] == {"variant": "hausdorff", "method": "prohd",
+                                 "backend": res.meta.backend, "n_a": 256, "n_b": 256,
+                                 "d": 16}
+        assert spans["hd.validate"]["attrs"]["bytes"] == a.nbytes + b.nbytes
+        # the validation runs inside the call, before the dispatch
+        v, dsp = spans["hd.validate"], spans["hd.dispatch"]
+        assert root["t_start"] <= v["t_start"] <= dsp["t_start"]
+        assert v["t_start"] + v["dur_s"] <= root["t_start"] + root["dur_s"] + 1e-3
+
+    def test_off_no_events_same_result(self):
+        a, b = _clouds(seed=1)
+        off = _prohd_call(a, b)
+        assert trace.events() == []
+        with trace.capture():
+            on = _prohd_call(a, b)
+        for x, y in ((off.value, on.value), (off.lower, on.lower), (off.upper, on.upper)):
+            assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+
+    def test_no_span_under_jit(self):
+        import jax
+
+        from repro.hd import HDConfig, HDEngine
+
+        a, b = _clouds(seed=2)
+        engine = HDEngine(method="prohd", config=HDConfig(alpha=0.05))
+        with trace.capture() as get_events:
+            jax.block_until_ready(jax.jit(lambda x, y: engine(x, y).value)(a, b))
+            events = get_events()
+        assert not [e for e in events if e["name"].startswith("hd.")]
+
+    def test_validate_false_opens_no_validate_span(self):
+        from repro.hd import set_distance
+
+        a, b = _clouds(seed=3)
+        with trace.capture() as get_events:
+            set_distance(a, b, validate=False)
+            names = [e["name"] for e in get_events()]
+        assert names == ["hd.dispatch", "hd.set_distance"]
+
+
+class TestCaptureRestores:
+    @pytest.mark.parametrize("xla", [False, True])
+    def test_prior_settings_come_back(self, tmp_path, xla):
+        outer = tmp_path / "outer.jsonl"
+        trace.enable(jsonl=outer, xla=xla)
+        with trace.span("before"):
+            pass
+        with trace.capture() as get_events:
+            with trace.span("inside"):
+                pass
+            assert [e["name"] for e in get_events()] == ["inside"]
+        assert trace.enabled() and trace._STATE.xla is xla
+        with trace.span("after"):
+            pass
+        trace.disable()
+        assert [r["name"] for r in export.read_jsonl(outer)] == ["before", "after"]
+
+    def test_off_stays_off(self):
+        with trace.capture():
+            pass
+        assert not trace.enabled() and not trace._STATE.xla and trace._STATE.jsonl is None
+
+
+# ---------------------------------------------------------------------------
+# obs.xla: a profile's device ops resolved to ProHD's named scopes
+# ---------------------------------------------------------------------------
+
+
+def _profile_ops(tmp_path, fn) -> dict[str, str]:
+    """``{op name: hlo_module}`` of the ops a CPU profile of ``fn`` records."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    ops = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                stats = dict(e.stats)
+                if "hlo_op" in stats and e.duration_ns > 0:
+                    ops[e.name.split(" = ")[0]] = stats.get("hlo_module", "")
+    return ops
+
+
+class TestXlaScopes:
+    @pytest.fixture(autouse=True)
+    def _fresh_notes(self):
+        from repro.obs import xla
+
+        xla.clear()
+        yield
+        xla.clear()
+
+    def test_profiled_ops_map_to_the_four_scopes(self, tmp_path):
+        import jax
+
+        from repro.core.prohd import SCOPES
+        from repro.obs import xla
+
+        a, b = _clouds(seed=4)
+        jax.block_until_ready(_prohd_call(a, b).value)
+        with trace.capture():
+            ops = _profile_ops(tmp_path, lambda: jax.block_until_ready(_prohd_call(a, b).value))
+        assert len(xla._NOTES) == 1
+        scopes = xla.op_scopes()
+        mine = [xla.bare(name) for name, module in ops.items() if module == "jit_prohd"]
+        assert {scopes.get(n) for n in mine} >= set(SCOPES)
+        # an op left out carries no op_name: the compiler made it
+        (text,) = xla._TEXTS.values()
+        named = xla.scopes_from_hlo(text, ("",))
+        assert [n for n in mine if n not in scopes and n in named] == []
+
+    def test_searchsorted_loop_body_is_the_lower_bound(self):
+        import re
+
+        from repro.core.prohd import ProHDConfig, prohd
+        from repro.obs import xla
+
+        a, b = _clouds(seed=5)
+        text = prohd.lower(a, b, ProHDConfig(alpha=0.05)).compile().as_text()
+        scopes = xla.scopes_from_hlo(text, ("prohd.",))
+        bodies = re.findall(r"while\(.*?body=%?([\w.\-]+).*searchsorted", text)
+        assert bodies
+        for body in set(bodies):
+            block = re.search(r"^%?" + re.escape(body) + r" .*?\{\n(.*?)\n\}", text,
+                              re.M | re.S).group(1)
+            named = re.findall(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*\bop_name=", block, re.M)
+            assert named and {scopes.get(n) for n in named} == {"prohd.lower_bound"}
+
+    def test_note_lowers_nothing_in_the_window(self):
+        import jax
+        import jax.monitoring
+
+        from repro.obs import xla
+
+        a, b = _clouds(seed=6)
+        jax.block_until_ready(_prohd_call(a, b).value)  # warm
+        seen = {"on": False, "compiles": 0, "traces": 0}
+
+        def listen(name, _secs, **_kw):
+            if seen["on"]:
+                seen["compiles"] += name == "/jax/core/compile/backend_compile_duration"
+                seen["traces"] += name == "/jax/core/compile/jaxpr_trace_duration"
+
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        seen["on"] = True
+        with trace.capture(xla=True):
+            jax.block_until_ready(_prohd_call(a, b).value)
+        seen["on"] = False
+        assert (seen["compiles"], seen["traces"]) == (0, 0)
+        assert len(xla._NOTES) == 1
+        assert set(xla.op_scopes().values()) >= {"prohd.lower_bound", "prohd.select"}
+
+    def test_note_skips_tracers_and_dedups(self):
+        import jax
+
+        from repro.core.prohd import ProHDConfig, prohd
+        from repro.obs import xla
+
+        a, b = _clouds(seed=7)
+        cfg = ProHDConfig(alpha=0.05)
+        jax.jit(lambda x, y: xla.note(prohd, x, y, cfg) or x.sum())(a, b)
+        assert xla._NOTES == {}
+        xla.note(prohd, a, b, cfg, key=None)
+        xla.note(prohd, a + 1, b, cfg, key=None)
+        assert len(xla._NOTES) == 1
+
+    def test_scopes_only_change_metadata(self, monkeypatch):
+        """The scoped program is the unscoped one but for its metadata."""
+        import contextlib
+        import re
+
+        import jax
+
+        from repro.core.prohd import ProHDConfig, prohd
+
+        a, b = _clouds(seed=8)
+        cfg = ProHDConfig(alpha=0.05)
+
+        def program():
+            text = prohd.lower(a, b, cfg).compile().as_text()
+            keep = [ln for ln in text.splitlines() if " = " in ln or ln.endswith("{")]
+            return text, [re.sub(r", (metadata=\{[^}]*\}|stack_frame_id=\d+)", "", ln)
+                          for ln in keep]
+
+        scoped_text, scoped = program()
+        jax.clear_caches()
+        monkeypatch.setattr(jax, "named_scope", lambda _name: contextlib.nullcontext())
+        plain_text, plain = program()
+        jax.clear_caches()
+        assert "prohd.lower_bound" in scoped_text and "prohd.lower_bound" not in plain_text
+        assert scoped == plain
