@@ -634,6 +634,11 @@ class TestXlaScopes:
         assert [n for n in mine if n not in scopes and n in named] == []
 
     def test_searchsorted_loop_body_is_the_lower_bound(self):
+        """The lower bound's merge sort and the ops fed by it, the scans
+        among them, map to ``prohd.lower_bound`` and to no other scope.
+        XLA:CPU leaves some of its rewrites without an ``op_name``, so
+        test_tpu_compile.py checks that every op of the chip's compile
+        carries the scope ``lower_bound_ms`` reads."""
         import re
 
         from repro.core.prohd import ProHDConfig, prohd
@@ -642,13 +647,21 @@ class TestXlaScopes:
         a, b = _clouds(seed=5)
         text = prohd.lower(a, b, ProHDConfig(alpha=0.05)).compile().as_text()
         scopes = xla.scopes_from_hlo(text, ("prohd.",))
-        bodies = re.findall(r"while\(.*?body=%?([\w.\-]+).*searchsorted", text)
-        assert bodies
-        for body in set(bodies):
-            block = re.search(r"^%?" + re.escape(body) + r" .*?\{\n(.*?)\n\}", text,
-                              re.M | re.S).group(1)
-            named = re.findall(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*\bop_name=", block, re.M)
-            assert named and {scopes.get(n) for n in named} == {"prohd.lower_bound"}
+        entry = text[text.index("\nENTRY"):]
+        instr = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? ([\w\-]+)\((.*)$", re.M)
+        ops = {name: (opcode, set(re.findall(r"%([\w.\-]+)", rest)))
+               for name, opcode, rest in instr.findall(entry)}
+        sorts = re.findall(r"^\s*%?([\w.\-]+) = .* sort\(.*op_name=\"[^\"]*projected_hd\)/sort\"",
+                           entry, re.M)
+        assert sorts
+        fed, grew = set(sorts), True
+        while grew:
+            more = {n for n, (_, args) in ops.items() if args & fed} - fed
+            fed |= more
+            grew = bool(more)
+        named = {n for n in fed if n in scopes}
+        assert len(named) > len(sorts)
+        assert {scopes[n] for n in named} == {"prohd.lower_bound"}
 
     def test_note_lowers_nothing_in_the_window(self):
         import jax

@@ -91,3 +91,31 @@ def test_multiquery_kernel_compiles(one_chip, cap):
     )
     assert "tpu_custom_call" in text
     assert used < HBM_BYTES
+
+
+def test_lower_bound_ops_keep_their_scope(one_chip):
+    """ProHD's certified lower bound compiles for the chip to one sort and
+    elementwise passes, and every op the chip runs for it carries
+    ``prohd.lower_bound``, which ``lower_bound_ms`` reads.  Left out: the
+    compiler's own memory-space copies, which carry no ``op_name``."""
+    import re
+
+    from repro.core.projected import projected_hd
+    from repro.core.prohd import LOWER_BOUND
+    from repro.obs import xla
+
+    def fn(proj_a, proj_b):
+        with jax.named_scope(LOWER_BOUND):
+            return projected_hd(proj_a, proj_b)
+
+    n = 8192
+    text, _ = _compile(fn, one_chip, ((n, 17), jnp.float32), ((n, 17), jnp.float32))
+    entry = text[text.index("\nENTRY"):]
+    ops = dict(re.findall(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? ([\w\-]+)\(", entry, re.M))
+    free = {"parameter", "constant", "bitcast", "get-tuple-element", "tuple",
+            "copy-start", "copy-done"}
+    run = [name for name, opcode in ops.items() if opcode not in free]
+    assert "sort" in ops.values()
+    assert not {"while", "gather"} & set(ops.values())
+    scopes = xla.scopes_from_hlo(text, ("prohd.",))
+    assert {scopes.get(name) for name in run} == {LOWER_BOUND}
